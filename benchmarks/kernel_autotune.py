@@ -39,6 +39,7 @@ from repro.core import packing
 from repro.kernels import interpret_mode
 from repro.kernels.sps_attn import kernel as sps_kernel
 from repro.kernels.sps_attn import ref as sps_ref
+from repro.launch.runtime import use_compile_cache
 
 PATHS = ("vpu", "mxu")
 DEFAULT_BLOCKS = (128, 256, 512)
@@ -124,6 +125,7 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
 
+    use_compile_cache()
     if args.tiny:
         h, l, iters = 2, 96, 2
         blocks = tuple(args.blocks) if args.blocks else TINY_BLOCKS

@@ -80,6 +80,7 @@ import jax
 import numpy as np
 
 from repro.configs import base
+from repro.launch.runtime import deploy_params, use_compile_cache
 from repro.models.lm import build_model
 from repro.serve import kvcache, trace as trace_lib
 from repro.serve.engine import (CacheConfig, PolicyConfig, Request,
@@ -302,8 +303,9 @@ def main(argv=None):
     cfg = base.get_smoke_config(args.arch)
     if cfg.skip_decode or cfg.frontend_tokens:
         raise SystemExit(f"{args.arch} has no token-only decode face")
+    use_compile_cache()
     model = build_model(cfg)
-    dparams = model.convert(model.init(jax.random.PRNGKey(args.seed)))
+    dparams = deploy_params(model, args.seed)
     max_len = args.shared_prefix + args.max_prompt + args.max_new + 8
     rng = np.random.default_rng(args.seed)
     reqs = make_trace(rng, args.requests, cfg.vocab_size,

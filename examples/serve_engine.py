@@ -17,15 +17,18 @@ Frontend (vlm/audio) archs serve via the static equal-length path.
 
 Run:  PYTHONPATH=src python examples/serve_engine.py \
           [--arch smollm-135m|mixtral-8x22b|hymba-1.5b|xlstm-350m] \
-          [--paged [--num-pages N]]
+          [--paged [--num-pages N]] [--full]
+
+``--full`` serves the published widths; the default is the reduced smoke
+config (CPU scale).
 """
 import argparse
 import time
 
-import jax
 import numpy as np
 
 from repro.configs import base
+from repro.launch.runtime import deploy_params, use_compile_cache
 from repro.models.lm import build_model
 from repro.serve.engine import (CacheConfig, PolicyConfig, Request,
                                 ServeConfig, ServeEngine, SpecConfig)
@@ -62,12 +65,16 @@ def main():
     p.add_argument("--spec-draft-layers", type=int, default=1,
                    help="layer-truncated draft depth (shares the trunk's "
                         "packed weights)")
+    p.add_argument("--full", action="store_true",
+                   help="published widths (default: the reduced smoke "
+                        "config)")
     args = p.parse_args()
 
-    cfg = base.get_smoke_config(args.arch)
+    cfg = (base.get_config(args.arch) if args.full
+           else base.get_smoke_config(args.arch))
+    use_compile_cache()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    dparams = model.convert(params)
+    dparams = deploy_params(model, 0)
     max_len = args.max_prompt + args.new_tokens + cfg.frontend_tokens + 8
     # frontend archs serve via the static path, which is contiguous-only
     paged = args.paged and not cfg.frontend_tokens

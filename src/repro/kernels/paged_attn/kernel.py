@@ -10,12 +10,12 @@ stages, and the same discipline applies to paging: the block table is an
 *address* structure, so resolve it in the kernel's index map instead of in
 data movement.
 
-Grid: ``(B, num_blocks)``, pages innermost.  The block table (plus
+Grid: ``(B, Hkv, num_blocks)``, pages innermost.  The block table (plus
 per-sequence lengths and the logical ring length) rides in as
 scalar-prefetch operands — Mosaic reads ``block_table[b, j]`` while
-scheduling the DMA for grid step ``(b, j)``, so each K/V^T page streams
-from HBM into VMEM exactly once and the gathered ring view NEVER exists.
-Per step the kernel
+scheduling the DMA for grid step ``(b, kh, j)``, so each kv head's K/V^T
+page streams from HBM into VMEM exactly once and the gathered ring view
+NEVER exists.  Per step the kernel
 
   1. scores the slot's one query token against the page's packed K rows
      (XNOR + popcount, the RBMM engine's M2 mode),
@@ -45,42 +45,42 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.packing import WORD
 
 
-def _kernel(bt_ref, len_ref, ring_ref, q_ref, kp_ref, vt_ref, th_ref,
-            out_ref, *, d_h: int, page: int, groups: int):
-    b, j = pl.program_id(0), pl.program_id(1)
-    q = q_ref[0]                                  # (H, dhp)
-    k = kp_ref[0]                                 # (Hkv, page, dhp)
-    vt = vt_ref[0]                                # (Hkv, d_h, page/32)
-    hkv, _, dhp = k.shape
-    h = hkv * groups
-    # M2: XNOR + popcount scores, one query row per kv-head group
-    qg = q.reshape(hkv, groups, dhp)
-    x = ~(qg[:, :, None, :] ^ k[:, None, :, :])   # (Hkv, G, page, dhp)
-    pc = lax.population_count(x).astype(jnp.int32).sum(-1)
+def _kernel(bt_ref, len_ref, ring_ref, qt_ref, kp_ref, vt_ref, th_ref,
+            out_ref, *, d_h: int, page: int):
+    # One (sequence, kv head, page) step on 2-D int32 tiles, ring columns
+    # on sublanes and the kv head's G query heads on lanes: Mosaic lowers
+    # neither unsigned reductions nor reshapes that split the head axis.
+    b, j = pl.program_id(0), pl.program_id(2)
+    qt = qt_ref[0, 0]                             # (dhp, G) query words
+    k = kp_ref[0, 0]                              # (page, dhp)
+    vt = vt_ref[0, 0]                             # (d_h, page/32)
+    dhp = k.shape[1]
+    # M2: XNOR + popcount scores, word by word -> (page, G)
+    pc = sum(lax.population_count(~(k[:, w:w + 1] ^ qt[w:w + 1, :]))
+             for w in range(dhp))
     pad = dhp * WORD - d_h
     c = 2 * pc - jnp.int32(d_h + 2 * pad)         # integer scores
     # SPS polarization + ring validity (trash-page cols are always masked)
-    th = th_ref[0].reshape(hkv, groups, 1)
-    cols = j * page + lax.broadcasted_iota(jnp.int32, (page,), 0)
+    cols = j * page + lax.broadcasted_iota(jnp.int32, (page, 1), 0)
     valid = (cols <= len_ref[b]) & (cols < ring_ref[0])
-    probs = jnp.where(valid[None, None, :],
-                      (c >= th).astype(jnp.uint32), jnp.uint32(0))
-    nnz = probs.sum(-1, dtype=jnp.int32)          # (Hkv, G)
-    # in-flight pack -> M3 and_dc against the page's packed V^T words
-    pows = jnp.uint32(1) << lax.broadcasted_iota(jnp.uint32, (WORD,), 0)
-    pw = probs.reshape(hkv, groups, page // WORD, WORD)
-    pp = (pw * pows[None, None, None, :]).sum(-1).astype(jnp.uint32)
-    y = pp[:, :, None, :] & vt[:, None, :, :]     # (Hkv, G, d_h, page/32)
-    pc2 = lax.population_count(y).astype(jnp.int32).sum(-1)
-    part = (2 * pc2 - nnz[..., None]).reshape(h, d_h)
+    probs = ((c >= th_ref[0, 0]) & valid).astype(jnp.int32)
+    nnz = probs.sum(0, keepdims=True)             # (1, G)
+    # in-flight pack (bits are disjoint, so the int32 sum is the OR and
+    # 1 << 31 lands as the sign bit) -> M3 and_dc against V^T words
+    pows = jnp.int32(1) << lax.broadcasted_iota(jnp.int32, (WORD, 1), 0)
+    y = 0
+    for w in range(page // WORD):
+        pw = (probs[w * WORD:(w + 1) * WORD] * pows).sum(0, keepdims=True)
+        y = y + lax.population_count(vt[:, w:w + 1] & pw)   # (d_h, G)
+    part = 2 * y - nnz
 
     @pl.when(j == 0)
     def _init():
-        out_ref[0] = part
+        out_ref[0, 0] = part
 
     @pl.when(j > 0)
     def _acc():
-        out_ref[0] += part
+        out_ref[0, 0] += part
 
 
 @functools.partial(jax.jit, static_argnames=("d_h", "interpret"))
@@ -102,28 +102,35 @@ def paged_gather_decode(q_bits: jax.Array, k_pages: jax.Array,
     """
     b, h, dhp = q_bits.shape
     npages, hkv, page, _ = k_pages.shape
+    g = h // hkv
     nblk = block_table.shape[1]
+    i32 = lambda x: lax.bitcast_convert_type(x, jnp.int32)
     bt = jnp.clip(block_table, 0, npages - 1).astype(jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32).reshape(b)
     ring = jnp.asarray(ring_len, jnp.int32).reshape(1)
-    th = jnp.asarray(theta, jnp.int32).reshape(b, h)
+    # heads of one kv group on the minor (lane) axis
+    qt = i32(q_bits).reshape(b, hkv, g, dhp).swapaxes(2, 3)
+    th = jnp.asarray(theta, jnp.int32).reshape(b, hkv, 1, g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,   # block_table, lengths, ring_len
-        grid=(b, nblk),
+        grid=(b, hkv, nblk),
         in_specs=[
-            pl.BlockSpec((1, h, dhp), lambda bb, j, bt, ln, rg: (bb, 0, 0)),
-            pl.BlockSpec((1, hkv, page, dhp),
-                         lambda bb, j, bt, ln, rg: (bt[bb, j], 0, 0, 0)),
-            pl.BlockSpec((1, hkv, d_h, page // WORD),
-                         lambda bb, j, bt, ln, rg: (bt[bb, j], 0, 0, 0)),
-            pl.BlockSpec((1, h), lambda bb, j, bt, ln, rg: (bb, 0)),
+            pl.BlockSpec((1, 1, dhp, g),
+                         lambda bb, kh, j, bt, ln, rg: (bb, kh, 0, 0)),
+            pl.BlockSpec((1, 1, page, dhp),
+                         lambda bb, kh, j, bt, ln, rg: (bt[bb, j], kh, 0, 0)),
+            pl.BlockSpec((1, 1, d_h, page // WORD),
+                         lambda bb, kh, j, bt, ln, rg: (bt[bb, j], kh, 0, 0)),
+            pl.BlockSpec((1, 1, 1, g),
+                         lambda bb, kh, j, bt, ln, rg: (bb, kh, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, d_h),
-                               lambda bb, j, bt, ln, rg: (bb, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, d_h, g),
+                               lambda bb, kh, j, bt, ln, rg: (bb, kh, 0, 0)),
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, d_h=d_h, page=page, groups=h // hkv),
+    out = pl.pallas_call(
+        functools.partial(_kernel, d_h=d_h, page=page),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d_h), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, d_h, g), jnp.int32),
         interpret=interpret,
-    )(bt, lens, ring, q_bits, k_pages, vt_pages, th)
+    )(bt, lens, ring, qt, i32(k_pages), i32(vt_pages), th)
+    return out.swapaxes(2, 3).reshape(b, h, d_h)

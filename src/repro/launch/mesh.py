@@ -10,9 +10,20 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Params = Any
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence[Any]] = None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes.  The model and trainer state their
+    shardings as specs and leave propagation to the compiler; Explicit axes
+    (``jax.make_mesh``'s default) would make every gather and constraint
+    spell out its output sharding."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -21,13 +32,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     (DCN/inter-pod) axis and carries only DP traffic."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1) -> Mesh:
     """Tiny mesh over however many real devices exist (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return make_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:

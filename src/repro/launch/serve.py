@@ -1,17 +1,20 @@
 """Serving launcher CLI: convert-to-deploy + batched generation.
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
-      --batch 4 --prompt-len 16 --new-tokens 32
+      --batch 4 --prompt-len 16 --new-tokens 32 [--full]
+
+``--full`` serves the published widths; the default is the reduced smoke
+config (CPU scale).
 """
 from __future__ import annotations
 
 import argparse
 import time
 
-import jax
 import numpy as np
 
 from repro.configs import base
+from repro.launch.runtime import deploy_params, use_compile_cache
 from repro.models.lm import build_model
 from repro.serve.engine import CacheConfig, ServeConfig, ServeEngine
 
@@ -28,14 +31,18 @@ def main() -> None:
                    choices=["greedy", "temperature", "top_k"])
     p.add_argument("--temperature", type=float, default=0.8)
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--full", action="store_true",
+                   help="published widths (default: the reduced smoke "
+                        "config)")
     args = p.parse_args()
 
-    cfg = base.get_smoke_config(args.arch)
+    cfg = (base.get_config(args.arch) if args.full
+           else base.get_smoke_config(args.arch))
     if cfg.skip_decode:
         raise SystemExit(f"{args.arch} is encoder-only: no decode face")
+    use_compile_cache()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    dparams = model.convert(params)
+    dparams = deploy_params(model, args.seed)
     max_len = args.max_len or (args.prompt_len + args.new_tokens +
                                cfg.frontend_tokens + 8)
     eng = ServeEngine(model, dparams,

@@ -19,6 +19,7 @@ from repro.checkpoint.ckpt import Checkpointer
 from repro.configs import base
 from repro.data.synthetic import SyntheticStream
 from repro.launch import mesh as mesh_lib
+from repro.launch.runtime import use_compile_cache
 from repro.models.lm import build_model
 from repro.optim.adamw import AdamW
 from repro.optim.schedules import warmup_cosine
@@ -47,6 +48,7 @@ def main() -> None:
 
     cfg = (base.get_smoke_config(args.arch) if args.smoke
            else base.get_config(args.arch))
+    use_compile_cache()
     model = build_model(cfg)
     mesh = mesh_lib.make_host_mesh(model_axis=args.model_parallel)
     opt = AdamW(lr=args.lr, schedule=warmup_cosine(args.steps // 10 + 1,

@@ -14,9 +14,9 @@ One module, three execution faces, numerically identical where they overlap:
 
 Attention is *chunked over query rows everywhere* (lax.map over q-chunks):
 SPS has no softmax state, so chunks combine associatively and the l x l
-score matrix never materializes — this is the graph-level mirror of the
-fused Pallas kernel (repro.kernels.sps_attn), which replaces the chunk body
-on real TPU runs.
+score matrix never materializes.  ``repro.kernels.sps_attn`` is a fused
+Pallas form of the same chunk body; no model path calls it (only its tests
+do), and the TPU compiler does not accept it yet.
 
 Supports GQA (kv heads broadcast to q heads), RoPE (applied on the fp
 projections *before* per-head binarization; BERT-style archs skip it and use

@@ -35,7 +35,7 @@ def test_train_step_on_8_devices():
 
         cfg = base.get_smoke_config('smollm-135m')
         model = build_model(cfg)
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = mesh_lib.make_mesh((4, 2), ('data', 'model'))
         tr = Trainer(model, AdamW(lr=1e-3), mesh, TrainerConfig())
         stream = SyntheticStream(cfg, 16, 8, seed=0)
         state = tr.init_state()
@@ -52,7 +52,7 @@ def test_fsdp_specs_assignment():
         from jax.sharding import PartitionSpec as P
         from repro.launch import mesh as mesh_lib
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = mesh_lib.make_mesh((4, 2), ('data', 'model'))
         specs = {'w': P(None, 'model'), 'small': P(None), 'odd': P(None, None)}
         shapes = {'w': jax.ShapeDtypeStruct((16, 6), jnp.float32),
                   'small': jax.ShapeDtypeStruct((7,), jnp.float32),
@@ -76,18 +76,19 @@ def test_elastic_rescale_8_to_4():
         from repro.train.trainer import Trainer, TrainerConfig
         from repro.train import ft
         from repro.checkpoint.ckpt import Checkpointer
+        from repro.launch import mesh as mesh_lib
 
         cfg = base.get_smoke_config('smollm-135m')
         model = build_model(cfg)
         stream = SyntheticStream(cfg, 16, 8, seed=0)
-        mesh8 = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh8 = mesh_lib.make_mesh((4, 2), ('data', 'model'))
         tr8 = Trainer(model, AdamW(lr=1e-3), mesh8, TrainerConfig())
         with tempfile.TemporaryDirectory() as d:
             ck = Checkpointer(d)
             state = ft.run(tr8, stream, ck, steps=2, ckpt_every=0,
                            log_every=100, log_fn=lambda s: None)
             # rescale: same checkpoint, (2,2) mesh of 4 devices
-            mesh4 = jax.make_mesh((2, 2), ('data', 'model'))
+            mesh4 = mesh_lib.make_mesh((2, 2), ('data', 'model'))
             tr4 = Trainer(model, AdamW(lr=1e-3), mesh4, TrainerConfig())
             st4, dstep, _ = ft.elastic_restore(ck, tr4)
             for x, y in zip(jax.tree.leaves(state.params),
@@ -103,15 +104,15 @@ def test_allreduce_1bit_shard_map():
     out = _run(4, """
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
+        from repro.launch import mesh as mesh_lib
         from repro.optim.compress import allreduce_1bit
 
-        mesh = jax.make_mesh((4,), ('data',))
+        mesh = mesh_lib.make_mesh((4,), ('data',))
         g = jnp.asarray(np.random.default_rng(0)
                         .normal(size=(4, 64)).astype(np.float32))
 
-        @partial(shard_map, mesh=mesh, in_specs=P('data', None),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P('data', None),
                  out_specs=P('data', None))
         def reduce(local):
             return allreduce_1bit(local[0], 'data')[None]
@@ -131,8 +132,9 @@ def test_allreduce_1bit_shard_map():
 def test_activation_sharding_context():
     out = _run(4, """
         import jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from repro.models.sharding import activation_sharding, constrain
-        mesh = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh = mesh_lib.make_mesh((2, 2), ('data', 'model'))
         x = jnp.ones((4, 8))
         # no-op outside the context
         assert constrain(x, 'batch', None) is x
