@@ -143,12 +143,13 @@ def rbmm_int(a: Array, b: Array, k: int, *, scheme: str = "xnor",
     """
     _check(scheme, impl)
     impl = resolve_impl(impl, a.shape[-2])
-    if impl in ("mxu", "dense"):
-        out = _rbmm_int_mxu(a, b, k, scheme)
-        if scheme == "and_dc" and dc is not None:
-            pass  # mxu path computes the true dot directly; dc not needed
-        return out
-    return _rbmm_int_popcount(a, b, k, scheme, dc)
+    # the path's ops carry ``rbmm_<impl>`` in their op metadata, so a
+    # profile can key per-path kernel time on the scope
+    with jax.named_scope(f"rbmm_{impl}"):
+        if impl in ("mxu", "dense"):
+            # the MXU path computes the true dot directly: no dc needed
+            return _rbmm_int_mxu(a, b, k, scheme)
+        return _rbmm_int_popcount(a, b, k, scheme, dc)
 
 
 # ---------------------------------------------------------------------------

@@ -722,8 +722,9 @@ class SPSAttention:
             m_pre = m_pre & (t_old[:, None, None, :] >
                              positions[:, None, :, None] - window)
         kc_h = self._repeat_kv(kc_old)
-        c_pre = rbmm.rbmm_int(q_bits, kc_h, dh, scheme="xnor",
-                              impl=self._score_impl())         # (B,H,C,W)
+        with jax.named_scope("sps_score"):
+            c_pre = rbmm.rbmm_int(q_bits, kc_h, dh, scheme="xnor",
+                                  impl=self._score_impl())     # (B,H,C,W)
         probs_pre = jnp.where(m_pre, (c_pre >= th).astype(jnp.uint32),
                               jnp.uint32(0))
         probs_p = packing.pack_bits(probs_pre)                 # (B,H,C,W/32)
@@ -735,8 +736,9 @@ class SPSAttention:
         ctx = 2 * pc - nnz[..., None]
         # intra-chunk causal block
         k_h = self._repeat_kv(k_bits)
-        c_in = rbmm.rbmm_int(q_bits, k_h, dh, scheme="xnor",
-                             impl=self._score_impl())          # (B,H,C,C)
+        with jax.named_scope("sps_score"):
+            c_in = rbmm.rbmm_int(q_bits, k_h, dh, scheme="xnor",
+                                 impl=self._score_impl())      # (B,H,C,C)
         i_idx = jnp.arange(c_len)
         m_in = (i_idx[None, :, None] >= i_idx[None, None, :]) & \
                (i_idx[None, None, :] < valid[:, None, None])
@@ -798,10 +800,11 @@ class SPSAttention:
         hkv, dh = self.num_kv_heads, self.head_dim
         page = cache.k_pages.shape[2]
         w = cache.block_table.shape[1] * page
-        bt = jnp.clip(cache.block_table, 0, cache.k_pages.shape[0] - 1)
-        kc = jnp.moveaxis(cache.k_pages[bt], 1, 2).reshape(b, hkv, w, -1)
-        vc = jnp.moveaxis(cache.vt_pages[bt], 1, 3
-                          ).reshape(b, hkv, dh, w // packing.WORD)
+        with jax.named_scope("paged_gather"):
+            bt = jnp.clip(cache.block_table, 0, cache.k_pages.shape[0] - 1)
+            kc = jnp.moveaxis(cache.k_pages[bt], 1, 2).reshape(b, hkv, w, -1)
+            vc = jnp.moveaxis(cache.vt_pages[bt], 1, 3
+                              ).reshape(b, hkv, dh, w // packing.WORD)
         return kc, vc, cache.ring_len
 
     def _write_chunk(self, cache, k_bits: Array, s_v: Array, start: Array,
@@ -1013,20 +1016,21 @@ class SPSAttention:
         b = q_bits.shape[0]
         h, hkv, dh = self.num_heads, self.num_kv_heads, self.head_dim
         w = kc.shape[2]
-        if self.grouped_decode and self.groups > 1:
-            g = self.groups
-            qg = q_bits[:, :, 0].reshape(b, hkv, g, -1)       # (B,Hkv,G,dhp)
-            # xnor_popcount_score carries the Eq. 7 pad correction
-            # (-(d_h + 2*pad)); the old inline ``2*pc - dh`` silently
-            # dropped it, shifting every score for d_h % 32 != 0 (pinned
-            # in tests/test_models_deploy.py)
-            c = packing.xnor_popcount_score(
-                qg[:, :, :, None, :], kc[:, :, None, :, :], dh
-            ).reshape(b, h, 1, w)                             # (B,H,1,W)
-        else:
-            kc_h = self._repeat_kv(kc)                        # (B,H,W,dhp)
-            c = rbmm.rbmm_int(q_bits, kc_h, dh, scheme="xnor",
-                              impl=self._score_impl())        # (B,H,1,W)
+        with jax.named_scope("sps_score"):
+            if self.grouped_decode and self.groups > 1:
+                g = self.groups
+                qg = q_bits[:, :, 0].reshape(b, hkv, g, -1)   # (B,Hkv,G,dhp)
+                # xnor_popcount_score carries the Eq. 7 pad correction
+                # (-(d_h + 2*pad)); the old inline ``2*pc - dh`` silently
+                # dropped it, shifting every score for d_h % 32 != 0
+                # (pinned in tests/test_models_deploy.py)
+                c = packing.xnor_popcount_score(
+                    qg[:, :, :, None, :], kc[:, :, None, :, :], dh
+                ).reshape(b, h, 1, w)                         # (B,H,1,W)
+            else:
+                kc_h = self._repeat_kv(kc)                    # (B,H,W,dhp)
+                c = rbmm.rbmm_int(q_bits, kc_h, dh, scheme="xnor",
+                                  impl=self._score_impl())    # (B,H,1,W)
         theta = self._theta_int(params)
         if self.sps_granularity == "row":
             row = jnp.clip(pos, 0, ROW_TABLE - 1)             # (B,)
@@ -1146,12 +1150,13 @@ class SPSAttention:
             return out, cache._replace(k_pages=kp, vt_pages=vp,
                                        length=pos + 1)
         # gather the slot's pages into a contiguous-ring view
-        bt = jnp.clip(cache.block_table, 0, kp.shape[0] - 1)  # (B,nblk)
-        kc = kp[bt]                                   # (B,nblk,Hkv,page,dhp)
-        kc = jnp.moveaxis(kc, 1, 2).reshape(b, hkv, wg, -1)
-        vc = vp[bt]                                   # (B,nblk,Hkv,dh,p32)
-        vc = jnp.moveaxis(vc, 1, 3)                   # (B,Hkv,dh,nblk,p32)
-        vc = vc.reshape(b, hkv, dh, wg // packing.WORD)
+        with jax.named_scope("paged_gather"):
+            bt = jnp.clip(cache.block_table, 0, kp.shape[0] - 1)  # (B,nblk)
+            kc = kp[bt]                               # (B,nblk,Hkv,page,dhp)
+            kc = jnp.moveaxis(kc, 1, 2).reshape(b, hkv, wg, -1)
+            vc = vp[bt]                               # (B,nblk,Hkv,dh,p32)
+            vc = jnp.moveaxis(vc, 1, 3)               # (B,Hkv,dh,nblk,p32)
+            vc = vc.reshape(b, hkv, dh, wg // packing.WORD)
         cols = jnp.arange(wg)[None, :]
         valid = (cols <= pos[:, None]) & (cols < ring)        # (B,Wg)
         out = self._attend_cache(params, q_bits, kc, vc, pos, valid)

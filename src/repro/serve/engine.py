@@ -842,66 +842,70 @@ class ServeEngine:
                     f"({r.max_new_tokens}) exceeds the cache ring "
                     f"(max_len={self.cfg.max_len}); raise ServeConfig."
                     f"max_len")
-        policy = (self._policy_proto if self._policy_proto is not None
-                  else make_policy(self.cfg.policy))
-        for r in requests:
-            policy.add(r)
-        pool = kvcache.SlotPool(max(1, min(self.cfg.num_slots,
-                                           len(requests) or 1)))
-        chunk = self.cfg.prefill_chunk
-        # traffic clock + per-request latency stamps: arrival is the
-        # request's open-loop offset, first/last are token emission times
-        # (both on the same serve()-relative clock), so TTFT/TPOT and the
-        # SLO/goodput rollup fall out at the end.  Keyed by rid, so the
-        # stamps survive preemption and resume.
-        t0 = time.perf_counter()
-        metrics: Dict[int, Dict[str, Optional[float]]] = {
-            r.rid: {"arrival": float(getattr(r, "arrival_s", 0.0)),
-                    "first": None, "last": None}
-            for r in requests}
-        preempt_counts: Dict[str, int] = {}
-        # speculative decode rides the deferred-write verify attend,
-        # which is attention-only — recurrent families decode plainly
-        spec_k = self.cfg.spec_decode if (self.cfg.spec_decode and
-                                          self._ragged_ok) else None
-        # candidate write span per pure-decode step: the pending token
-        # plus the k drafted tokens (plain decode writes one position;
-        # mixed unified iterations also write exactly one per decode row)
-        span = (spec_k + 1) if spec_k else 1
-        arenas: Dict[int, kvcache.PageArena] = {}
-        rings: List[Optional[int]] = []
-        if spec:
-            rings = self._layer_rings(spec)
-            for ring in rings:
-                if ring is None or ring in arenas:
-                    continue
-                arenas[ring] = kvcache.PageArena(
-                    spec.arena_pages(ring, pool.num_slots), spec.page_size,
-                    pool.num_slots, spec.blocks_for_ring(ring), ring)
-            caches = self.model.init_caches(pool.num_slots,
-                                            self.cfg.max_len, paged=spec)
-        else:
-            caches = self.model.init_caches(pool.num_slots, self.cfg.max_len)
-        token_buf = np.zeros((pool.num_slots, 1), np.int32)
-        states: Dict[int, _SlotState] = {}
-        inflight: Dict[int, _PrefillState] = {}
-        results: Dict[int, np.ndarray] = {}
-        resumed: Dict[int, List[int]] = {}   # rid -> tokens before preempt
-        dcaches = None
-        if spec_k:
-            self._resolve_draft()
-            # the draft pool is contiguous (a small, unshared fraction of
-            # the trunk's footprint) but must cover the trunk's capacity
-            draft_len = spec.capacity if spec else self.cfg.max_len
-            dcaches = self.draft_model.init_caches(pool.num_slots,
-                                                   draft_len)
-            if self._spec_jit is None:
-                self._build_spec_step()
-        if not spec_k and self._decode_jit is None:
-            self._build_decode()
-        if self._unified_jit is None:
-            self._build_unified(bool(spec_k))
-        key = jax.random.PRNGKey(self.cfg.seed)
+        # serve.setup: policy, pool, page arenas and cache pools, before
+        # the first iteration (device allocations land in this span)
+        with jax.profiler.TraceAnnotation("serve.setup"):
+            policy = (self._policy_proto if self._policy_proto is not None
+                      else make_policy(self.cfg.policy))
+            for r in requests:
+                policy.add(r)
+            pool = kvcache.SlotPool(max(1, min(self.cfg.num_slots,
+                                               len(requests) or 1)))
+            chunk = self.cfg.prefill_chunk
+            # traffic clock + per-request latency stamps: arrival is the
+            # request's open-loop offset, first/last are token emission times
+            # (both on the same serve()-relative clock), so TTFT/TPOT and the
+            # SLO/goodput rollup fall out at the end.  Keyed by rid, so the
+            # stamps survive preemption and resume.
+            t0 = time.perf_counter()
+            metrics: Dict[int, Dict[str, Optional[float]]] = {
+                r.rid: {"arrival": float(getattr(r, "arrival_s", 0.0)),
+                        "first": None, "last": None}
+                for r in requests}
+            preempt_counts: Dict[str, int] = {}
+            # speculative decode rides the deferred-write verify attend,
+            # which is attention-only — recurrent families decode plainly
+            spec_k = self.cfg.spec_decode if (self.cfg.spec_decode and
+                                              self._ragged_ok) else None
+            # candidate write span per pure-decode step: the pending token
+            # plus the k drafted tokens (plain decode writes one position;
+            # mixed unified iterations also write exactly one per decode row)
+            span = (spec_k + 1) if spec_k else 1
+            arenas: Dict[int, kvcache.PageArena] = {}
+            rings: List[Optional[int]] = []
+            if spec:
+                rings = self._layer_rings(spec)
+                for ring in rings:
+                    if ring is None or ring in arenas:
+                        continue
+                    arenas[ring] = kvcache.PageArena(
+                        spec.arena_pages(ring, pool.num_slots), spec.page_size,
+                        pool.num_slots, spec.blocks_for_ring(ring), ring)
+                caches = self.model.init_caches(pool.num_slots,
+                                                self.cfg.max_len, paged=spec)
+            else:
+                caches = self.model.init_caches(pool.num_slots,
+                                                self.cfg.max_len)
+            token_buf = np.zeros((pool.num_slots, 1), np.int32)
+            states: Dict[int, _SlotState] = {}
+            inflight: Dict[int, _PrefillState] = {}
+            results: Dict[int, np.ndarray] = {}
+            resumed: Dict[int, List[int]] = {}   # rid -> tokens before preempt
+            dcaches = None
+            if spec_k:
+                self._resolve_draft()
+                # the draft pool is contiguous (a small, unshared fraction of
+                # the trunk's footprint) but must cover the trunk's capacity
+                draft_len = spec.capacity if spec else self.cfg.max_len
+                dcaches = self.draft_model.init_caches(pool.num_slots,
+                                                       draft_len)
+                if self._spec_jit is None:
+                    self._build_spec_step()
+            if not spec_k and self._decode_jit is None:
+                self._build_decode()
+            if self._unified_jit is None:
+                self._build_unified(bool(spec_k))
+            key = jax.random.PRNGKey(self.cfg.seed)
         prefill_batches = 0  # iterations that admitted >= 1 request
         prefill_chunks = 0   # chunk advances of chunk-split prompts
         preemptions = 0
@@ -913,6 +917,12 @@ class ServeEngine:
         spec_drafted = 0
         spec_accepted = 0
         peak_pages = 0       # true simultaneous peak across all arenas
+        passes = 0           # loop passes, dispatching or not
+        # work of the pooled chunk steps (EngineReport "work" group)
+        work = dict.fromkeys(("chunk_positions", "chunk_valid_positions",
+                              "prefill_positions",
+                              "prefill_positions_adopted"), 0)
+        queue_wait: Dict[int, float] = {}   # rid -> first admission wait
 
         def release_slot(slot: int) -> _SlotState:
             """Shared teardown: drop python state, free the pool slot and
@@ -970,6 +980,32 @@ class ServeEngine:
             peak_pages = max(peak_pages, sum(
                 a.used_pages for a in arenas.values()))
 
+        def cow_sweep(dspan: int,
+                      copies: Dict[int, List[Tuple[int, int]]]) -> bool:
+            """Privatize every shared page the next decode write (``dspan``
+            positions per decoding slot) would diverge, recording the
+            (old, new) payload copies in ``copies``; a sole owner's
+            divergent write retires the page's hash key instead.  False
+            when the arena has no page left for a copy (the caller
+            preempts and retries)."""
+            for ring, a in arenas.items():
+                for slot in sorted(states):
+                    base = states[slot].cache_len
+                    done_lp = set()
+                    for pos in range(base, base + dspan):
+                        lp, page = a.write_page(slot, pos)
+                        if page == 0 or lp in done_lp:
+                            continue
+                        done_lp.add(lp)
+                        if a.refcount(page) > 1:
+                            if not a.can_cow():
+                                return False
+                            copies.setdefault(ring, []).append(
+                                a.cow(slot, lp))
+                        elif a.page_key(page) is not None:
+                            a.invalidate_key(page)
+            return True
+
         def slo_endangered() -> bool:
             """True when any decoding row with a TPOT budget has gone
             more than half that budget since its last token — the
@@ -1010,251 +1046,282 @@ class ServeEngine:
             return st.push(tok)
 
         while policy or pool.active_count:
-            # -- admission: host bookkeeping only, no dispatch --------------
-            admitted_any = False
-            while policy and pool.free_count:
-                now_s = time.perf_counter() - t0
-                hint = chunk
-                if not hint and inflight:
-                    hint = _pow2_bucket(max(len(st.toks) - st.done
-                                            for st in inflight.values()))
-                req = policy.pop_admissible(now_s, hint)
-                if req is None:
-                    break
-                pre = resumed.get(req.rid, [])
-                plen = len(req.tokens) + len(pre)
-                slot = pool.alloc(req.rid)
-                if arenas and self.cfg.prefix_share:
-                    # hash-cons the prompt's full pages so this slot can
-                    # adopt pages an earlier sharer already maps (and
-                    # register the ones it allocates itself); resumed
-                    # tokens extend the chain, so a preempted request
-                    # still re-shares its original prompt prefix
-                    keys = self._page_keys(np.concatenate(
-                        [np.asarray(req.tokens, np.int32),
-                         np.asarray(pre, np.int32)]))
-                    for arena in arenas.values():
-                        arena.set_prefix_keys(slot, keys, plen)
-                # chunk-split prompts reserve only their FIRST chunk's
-                # pages now (the rest grows per iteration); whole-prompt
-                # admissions reserve prompt + first decode write — pages
-                # alone could otherwise prefill a request only for its
-                # own first growth step to preempt it straight back
-                reserve = chunk if (chunk and plen > chunk) else plen + 1
-                if arenas and not all(a.can_grow(slot, reserve)
-                                      for a in arenas.values()):
-                    for arena in arenas.values():
-                        arena.release(slot)   # drops the promises
-                    pool.release(slot)
-                    policy.requeue(req)       # no pages yet; retry later
-                    break
-                for arena in arenas.values():
-                    arena.grow(slot, reserve)
-                toks = np.concatenate(
-                    [np.asarray(req.tokens, np.int32),
-                     np.asarray(resumed.pop(req.rid, []), np.int32)])
-                inflight[slot] = _PrefillState(req, toks, pre, admit_seq)
-                policy.on_admit(req)
-                admit_seq += 1
-                admitted_any = True
-            if admitted_any:
-                prefill_batches += 1
-            if not (states or inflight):
-                # open-loop idle gap: everything queued is still in the
-                # future — sleep toward the next arrival (bounded, so an
-                # arena-exhaustion requeue retries promptly) instead of
-                # spinning the admission loop
-                nxt = policy.next_arrival_s()
-                if nxt is not None:
-                    gap = t0 + nxt - time.perf_counter()
-                    if gap > 0:
-                        time.sleep(min(gap, 0.005))
-                continue
-            # -- paged growth: cover every row's writes; preempt on
-            # exhaustion.  Prefill rows grow to their chunk end (+ the
-            # first decode write when it lands the prompt); decode rows
-            # grow by the write span (1 plain/mixed; k+1 speculative).
-            # The COW sweep privatizes shared pages a DECODE write would
-            # diverge — prefill-chunk writes never diverge a page (they
-            # write exactly the content its hash key promises), so
-            # in-flight rows need neither COW nor masking.
-            # the iteration's unified width is planned ONCE and shared by
-            # paged growth and the dispatch below: the adaptive-chunk
-            # hook reads the wall clock, and growing pages for one width
-            # but dispatching another could write pages growth never
-            # covered
-            width_now = plan_width() if inflight else 0
-            if arenas:
-                copies: Dict[int, List[Tuple[int, int]]] = {}
-                while states or inflight:
-                    ok = True
-                    dspan = span if (spec_k and not inflight) else 1
-                    width = width_now if inflight else 0
-                    for slot in sorted(set(states) | set(inflight)):
-                        if slot in inflight:
+            with jax.profiler.StepTraceAnnotation("serve.iteration",
+                                                  step_num=passes):
+                passes += 1
+                # -- admission: host bookkeeping only, no dispatch ----------
+                admitted_any = False
+                while policy and pool.free_count:
+                    with jax.profiler.TraceAnnotation("serve.admit") as adm:
+                        now_s = time.perf_counter() - t0
+                        hint = chunk
+                        if not hint and inflight:
+                            hint = _pow2_bucket(max(
+                                len(st.toks) - st.done
+                                for st in inflight.values()))
+                        req = policy.pop_admissible(now_s, hint)
+                        if req is None:
+                            break
+                        adm.set_metadata(rid=req.rid)
+                        pre = resumed.get(req.rid, [])
+                        plen = len(req.tokens) + len(pre)
+                        slot = pool.alloc(req.rid)
+                        if arenas and self.cfg.prefix_share:
+                            # hash-cons the prompt's full pages so this
+                            # slot can adopt pages an earlier sharer
+                            # already maps (and register the ones it
+                            # allocates itself); resumed tokens extend the
+                            # chain, so a preempted request still
+                            # re-shares its original prompt prefix
+                            keys = self._page_keys(np.concatenate(
+                                [np.asarray(req.tokens, np.int32),
+                                 np.asarray(pre, np.int32)]))
+                            for arena in arenas.values():
+                                arena.set_prefix_keys(slot, keys, plen)
+                        # chunk-split prompts reserve only their FIRST
+                        # chunk's pages now (the rest grows per
+                        # iteration); whole-prompt admissions reserve
+                        # prompt + first decode write — pages alone could
+                        # otherwise prefill a request only for its own
+                        # first growth step to preempt it straight back
+                        reserve = chunk if (chunk and plen > chunk) \
+                            else plen + 1
+                        if arenas and not all(a.can_grow(slot, reserve)
+                                              for a in arenas.values()):
+                            for arena in arenas.values():
+                                arena.release(slot)  # drops the promises
+                            pool.release(slot)
+                            policy.requeue(req)  # no pages yet; retry later
+                            break
+                        for arena in arenas.values():
+                            arena.grow(slot, reserve)
+                        toks = np.concatenate(
+                            [np.asarray(req.tokens, np.int32),
+                             np.asarray(resumed.pop(req.rid, []), np.int32)])
+                        inflight[slot] = _PrefillState(req, toks, pre,
+                                                       admit_seq)
+                        policy.on_admit(req)
+                        queue_wait.setdefault(
+                            req.rid, now_s - metrics[req.rid]["arrival"])
+                        admit_seq += 1
+                        admitted_any = True
+                if admitted_any:
+                    prefill_batches += 1
+                if not (states or inflight):
+                    # open-loop idle gap: everything queued is still in
+                    # the future — sleep toward the next arrival (bounded,
+                    # so an arena-exhaustion requeue retries promptly)
+                    # instead of spinning the admission loop
+                    nxt = policy.next_arrival_s()
+                    if nxt is not None:
+                        gap = t0 + nxt - time.perf_counter()
+                        if gap > 0:
+                            with jax.profiler.TraceAnnotation("serve.wait"):
+                                time.sleep(min(gap, 0.005))
+                    continue
+                # -- paged growth: cover every row's writes; preempt on
+                # exhaustion.  Prefill rows grow to their chunk end (+ the
+                # first decode write when it lands the prompt); decode
+                # rows grow by the write span (1 plain/mixed; k+1
+                # speculative).  The COW sweep privatizes shared pages a
+                # DECODE write would diverge — prefill-chunk writes never
+                # diverge a page (they write exactly the content its hash
+                # key promises), so in-flight rows need neither COW nor
+                # masking.
+                # the iteration's unified width is planned ONCE and shared
+                # by paged growth and the dispatch below: the
+                # adaptive-chunk hook reads the wall clock, and growing
+                # pages for one width but dispatching another could write
+                # pages growth never covered
+                width_now = plan_width() if inflight else 0
+                if arenas:
+                    copies: Dict[int, List[Tuple[int, int]]] = {}
+                    with jax.profiler.TraceAnnotation("serve.grow"):
+                        while states or inflight:
+                            ok = True
+                            dspan = span if (spec_k and not inflight) else 1
+                            width = width_now if inflight else 0
+                            for slot in sorted(set(states) | set(inflight)):
+                                if slot in inflight:
+                                    ist = inflight[slot]
+                                    n = min(width, len(ist.toks) - ist.done)
+                                    final = ist.done + n == len(ist.toks)
+                                    target = ist.done + n + (1 if final
+                                                             else 0)
+                                else:
+                                    target = states[slot].cache_len + dspan
+                                if not all(a.grow(slot, target)
+                                           for a in arenas.values()):
+                                    ok = False
+                                    break
+                            if ok:
+                                ok = cow_sweep(dspan, copies)
+                            if ok:
+                                break
+                            preempt(pick_victim())
+                            preemptions += 1
+                    if not (states or inflight):
+                        continue
+                    if copies:
+                        # apply payload copies BEFORE the step writes
+                        # anything: the (old, new) ids are snapshots of
+                        # the sweep-time page contents
+                        with jax.profiler.TraceAnnotation("serve.copy_pages"):
+                            caches = self._copy_pages(caches, rings, copies)
+                    peak()
+                    with jax.profiler.TraceAnnotation("serve.sync_tables"):
+                        caches = self._sync_tables(caches, arenas, rings)
+                # -- ONE pooled dispatch advances every in-flight stream ----
+                if inflight:
+                    # unified mixed iteration: prefill chunks + decode rows
+                    # fused in one forward (see _build_unified)
+                    width = width_now
+                    with jax.profiler.TraceAnnotation("serve.prepare"):
+                        toks_buf = np.zeros((pool.num_slots, width), np.int32)
+                        start_buf = np.zeros((pool.num_slots,), np.int32)
+                        valid_buf = np.zeros((pool.num_slots,), np.int32)
+                        fresh_buf = np.zeros((pool.num_slots,), bool)
+                        advance: Dict[int, int] = {}
+                        for slot in sorted(inflight):
                             ist = inflight[slot]
                             n = min(width, len(ist.toks) - ist.done)
-                            final = ist.done + n == len(ist.toks)
-                            target = ist.done + n + (1 if final else 0)
+                            toks_buf[slot, :n] = \
+                                ist.toks[ist.done:ist.done + n]
+                            start_buf[slot] = ist.done
+                            valid_buf[slot] = n
+                            fresh_buf[slot] = ist.done == 0
+                            advance[slot] = n
+                            if arenas:
+                                work["prefill_positions_adopted"] += int(
+                                    np.logical_and.reduce([
+                                        a.adopted_mask(slot, ist.done,
+                                                       ist.done + n)
+                                        for a in arenas.values()]).sum())
+                        for slot in sorted(states):
+                            toks_buf[slot, 0] = token_buf[slot, 0]
+                            start_buf[slot] = states[slot].cache_len
+                            valid_buf[slot] = 1
+                        work["chunk_positions"] += toks_buf.size
+                        work["chunk_valid_positions"] += int(valid_buf.sum())
+                        work["prefill_positions"] += sum(advance.values())
+                        toks_dev = jnp.asarray(toks_buf)
+                        rows_dev = (jnp.asarray(start_buf),
+                                    jnp.asarray(valid_buf),
+                                    jnp.asarray(fresh_buf))
+                    with jax.profiler.TraceAnnotation(
+                            "serve.dispatch.chunk"):
+                        if spec_k:
+                            nxt, caches, dcaches, key = self._unified_jit(
+                                self.dparams, self.draft_dparams, toks_dev,
+                                caches, dcaches, *rows_dev, key)
                         else:
-                            target = states[slot].cache_len + dspan
-                        if not all(a.grow(slot, target)
-                                   for a in arenas.values()):
-                            ok = False
-                            break
-                    if ok:
-                        for ring, a in arenas.items():
-                            for slot in sorted(states):
-                                base = states[slot].cache_len
-                                done_lp = set()
-                                for pos in range(base, base + dspan):
-                                    lp, page = a.write_page(slot, pos)
-                                    if page == 0 or lp in done_lp:
-                                        continue
-                                    done_lp.add(lp)
-                                    if a.refcount(page) > 1:
-                                        if not a.can_cow():
-                                            ok = False
-                                            break
-                                        copies.setdefault(ring, []).append(
-                                            a.cow(slot, lp))
-                                    elif a.page_key(page) is not None:
-                                        a.invalidate_key(page)
-                                if not ok:
+                            nxt, caches, key = self._unified_jit(
+                                self.dparams, toks_dev, caches, *rows_dev,
+                                key)
+                    iterations += 1
+                    dispatches += 1
+                    with jax.profiler.TraceAnnotation("serve.readback"):
+                        nxt_np = np.asarray(nxt)
+                    pool.tick(busy=len(states) + len(inflight))
+                    with jax.profiler.TraceAnnotation("serve.emit"):
+                        # decode rows first: a decoding slot's token
+                        # streams before the first token of a prefill
+                        # landing its final chunk in the same forward
+                        # (TTFT liveness ordering)
+                        for slot in sorted(states):
+                            st = states[slot]
+                            st.cache_len += 1
+                            tok = int(nxt_np[slot, 0])
+                            token_buf[slot, 0] = tok
+                            if emit(st, tok):
+                                retire(slot)
+                        for slot in sorted(inflight):
+                            ist = inflight[slot]
+                            n = advance[slot]
+                            if chunk and len(ist.toks) > chunk:
+                                prefill_chunks += 1
+                            ist.done += n
+                            if ist.done < len(ist.toks):
+                                continue
+                            del inflight[slot]
+                            sst = _SlotState(ist.request, self.cfg.eos_id,
+                                             len(ist.toks), ist.admit_seq,
+                                             ist.pre)
+                            states[slot] = sst
+                            tok = int(nxt_np[slot, 0])
+                            token_buf[slot, 0] = tok
+                            if emit(sst, tok):
+                                retire(slot)
+                elif spec_k:
+                    # pure-decode speculative iteration: draft k, verify
+                    # k+1, commit the accepted prefix — one jit
+                    with jax.profiler.TraceAnnotation("serve.prepare"):
+                        start_buf = np.zeros((pool.num_slots,), np.int32)
+                        active_buf = np.zeros((pool.num_slots,), bool)
+                        for s in states:
+                            start_buf[s] = states[s].cache_len
+                            active_buf[s] = True
+                        token_dev = jnp.asarray(token_buf)
+                        start_dev = jnp.asarray(start_buf)
+                        active_dev = jnp.asarray(active_buf)
+                    with jax.profiler.TraceAnnotation(
+                            "serve.dispatch.verify"):
+                        out, n_acc, nxt, caches, dcaches, key = \
+                            self._spec_jit(self.dparams, self.draft_dparams,
+                                           token_dev, caches, dcaches,
+                                           start_dev, active_dev, key)
+                    iterations += 1
+                    dispatches += 1
+                    with jax.profiler.TraceAnnotation("serve.readback"):
+                        out_np = np.asarray(out)
+                        n_np = np.asarray(n_acc)
+                        token_buf = np.asarray(nxt).copy()
+                    pool.tick(busy=len(states))
+                    spec_steps += 1
+                    spec_slot_steps += len(states)
+                    with jax.profiler.TraceAnnotation("serve.emit"):
+                        for slot in sorted(states):
+                            st = states[slot]
+                            n = int(n_np[slot])
+                            spec_drafted += spec_k
+                            spec_accepted += n
+                            st.cache_len += n + 1
+                            for i in range(n + 1):
+                                tok = int(out_np[slot, i])
+                                if emit(st, tok):
+                                    retire(slot)
                                     break
-                            if not ok:
-                                break
-                    if ok:
-                        break
-                    preempt(pick_victim())
-                    preemptions += 1
-                if not (states or inflight):
-                    continue
-                if copies:
-                    # apply payload copies BEFORE the step writes
-                    # anything: the (old, new) ids are snapshots of the
-                    # sweep-time page contents
-                    caches = self._copy_pages(caches, rings, copies)
-                peak()
-                caches = self._sync_tables(caches, arenas, rings)
-            # -- ONE pooled dispatch advances every in-flight stream --------
-            if inflight:
-                # unified mixed iteration: prefill chunks + decode rows
-                # fused in one forward (see _build_unified)
-                width = width_now
-                toks_buf = np.zeros((pool.num_slots, width), np.int32)
-                start_buf = np.zeros((pool.num_slots,), np.int32)
-                valid_buf = np.zeros((pool.num_slots,), np.int32)
-                fresh_buf = np.zeros((pool.num_slots,), bool)
-                advance: Dict[int, int] = {}
-                for slot in sorted(inflight):
-                    ist = inflight[slot]
-                    n = min(width, len(ist.toks) - ist.done)
-                    toks_buf[slot, :n] = ist.toks[ist.done:ist.done + n]
-                    start_buf[slot] = ist.done
-                    valid_buf[slot] = n
-                    fresh_buf[slot] = ist.done == 0
-                    advance[slot] = n
-                for slot in sorted(states):
-                    toks_buf[slot, 0] = token_buf[slot, 0]
-                    start_buf[slot] = states[slot].cache_len
-                    valid_buf[slot] = 1
-                if spec_k:
-                    nxt, caches, dcaches, key = self._unified_jit(
-                        self.dparams, self.draft_dparams,
-                        jnp.asarray(toks_buf), caches, dcaches,
-                        jnp.asarray(start_buf), jnp.asarray(valid_buf),
-                        jnp.asarray(fresh_buf), key)
+                        # speculative rollback, arena side: pages grown
+                        # for the candidate span un-grow back to exactly
+                        # the committed length (rejected-tail pages return
+                        # to the free list, counted as rollback frees,
+                        # never as retirements)
+                        if arenas:
+                            for slot in sorted(states):
+                                for a in arenas.values():
+                                    a.truncate(slot, states[slot].cache_len)
                 else:
-                    nxt, caches, key = self._unified_jit(
-                        self.dparams, jnp.asarray(toks_buf), caches,
-                        jnp.asarray(start_buf), jnp.asarray(valid_buf),
-                        jnp.asarray(fresh_buf), key)
-                iterations += 1
-                dispatches += 1
-                nxt_np = np.asarray(nxt)
-                pool.tick(busy=len(states) + len(inflight))
-                # decode rows first: a decoding slot's token streams
-                # before the first token of a prefill landing its final
-                # chunk in the same forward (TTFT liveness ordering)
-                for slot in sorted(states):
-                    st = states[slot]
-                    st.cache_len += 1
-                    tok = int(nxt_np[slot, 0])
-                    token_buf[slot, 0] = tok
-                    if emit(st, tok):
-                        retire(slot)
-                for slot in sorted(inflight):
-                    ist = inflight[slot]
-                    n = advance[slot]
-                    if chunk and len(ist.toks) > chunk:
-                        prefill_chunks += 1
-                    ist.done += n
-                    if ist.done < len(ist.toks):
-                        continue
-                    del inflight[slot]
-                    sst = _SlotState(ist.request, self.cfg.eos_id,
-                                     len(ist.toks), ist.admit_seq, ist.pre)
-                    states[slot] = sst
-                    tok = int(nxt_np[slot, 0])
-                    token_buf[slot, 0] = tok
-                    if emit(sst, tok):
-                        retire(slot)
-            elif spec_k:
-                # pure-decode speculative iteration: draft k, verify
-                # k+1, commit the accepted prefix — one jit
-                start_buf = np.zeros((pool.num_slots,), np.int32)
-                active_buf = np.zeros((pool.num_slots,), bool)
-                for s in states:
-                    start_buf[s] = states[s].cache_len
-                    active_buf[s] = True
-                out, n_acc, nxt, caches, dcaches, key = self._spec_jit(
-                    self.dparams, self.draft_dparams,
-                    jnp.asarray(token_buf), caches, dcaches,
-                    jnp.asarray(start_buf), jnp.asarray(active_buf), key)
-                iterations += 1
-                dispatches += 1
-                out_np = np.asarray(out)
-                n_np = np.asarray(n_acc)
-                pool.tick(busy=len(states))
-                token_buf = np.asarray(nxt).copy()
-                spec_steps += 1
-                spec_slot_steps += len(states)
-                for slot in sorted(states):
-                    st = states[slot]
-                    n = int(n_np[slot])
-                    spec_drafted += spec_k
-                    spec_accepted += n
-                    st.cache_len += n + 1
-                    for i in range(n + 1):
-                        tok = int(out_np[slot, i])
-                        if emit(st, tok):
-                            retire(slot)
-                            break
-                # speculative rollback, arena side: pages grown for the
-                # candidate span un-grow back to exactly the committed
-                # length (rejected-tail pages return to the free list,
-                # counted as rollback frees, never as retirements)
-                if arenas:
-                    for slot in sorted(states):
-                        for a in arenas.values():
-                            a.truncate(slot, states[slot].cache_len)
-            else:
-                # pure-decode iteration: the dedicated pooled decode step
-                # (deploy_decode — the fused paged kernel's home)
-                token, caches, key = self._decode_jit(
-                    self.dparams, jnp.asarray(token_buf), caches, key)
-                iterations += 1
-                dispatches += 1
-                toks = np.asarray(token)
-                pool.tick(busy=len(states))
-                token_buf = toks.copy()
-                for slot in sorted(states):
-                    st = states[slot]
-                    st.cache_len += 1
-                    tok = int(toks[slot, 0])
-                    if emit(st, tok):
-                        retire(slot)
+                    # pure-decode iteration: the dedicated pooled decode
+                    # step (deploy_decode — the fused paged kernel's home)
+                    with jax.profiler.TraceAnnotation("serve.prepare"):
+                        token_dev = jnp.asarray(token_buf)
+                    with jax.profiler.TraceAnnotation(
+                            "serve.dispatch.decode"):
+                        token, caches, key = self._decode_jit(
+                            self.dparams, token_dev, caches, key)
+                    iterations += 1
+                    dispatches += 1
+                    with jax.profiler.TraceAnnotation("serve.readback"):
+                        toks = np.asarray(token)
+                    pool.tick(busy=len(states))
+                    token_buf = toks.copy()
+                    with jax.profiler.TraceAnnotation("serve.emit"):
+                        for slot in sorted(states):
+                            st = states[slot]
+                            st.cache_len += 1
+                            tok = int(toks[slot, 0])
+                            if emit(st, tok):
+                                retire(slot)
 
         report = kvcache.cache_report(
             caches,
@@ -1274,6 +1341,9 @@ class ServeEngine:
         report.requests = float(len(requests))
         report.spec_steps = float(spec_steps)
         report.preemptions = float(preemptions)
+        for name, n in work.items():
+            report[name] = float(n)
+        report.queue_wait_s = queue_wait
         if spec:
             # cache_report sums per-arena peaks, which can land on
             # different steps; replace with the per-step simultaneous

@@ -80,6 +80,16 @@ class EngineReport:
       spec        spec_drafted .. spec_tokens_per_step, spec_steps
       engine      iterations .. engine_compiles, prefill_batches,
                   prefill_chunks, requests, preemptions
+      work        filled by every ``serve()`` call: chunk_positions
+                  (pool rows x chunk width, summed over the pooled chunk
+                  steps), chunk_valid_positions (those that carried a
+                  token: prefill tokens plus one per decode row),
+                  prefill_positions (prompt positions the chunk steps
+                  computed), prefill_positions_adopted (the part whose
+                  page every arena had adopted from a sharer, so the
+                  step recomputed K/V^T that was already cached),
+                  queue_wait_s ({rid: seconds from ``arrival_s`` to first
+                  admission}, on ``serve()``'s clock)
       traffic     elapsed_s, goodput_under_slo (SLO-meeting requests'
                   tokens per second), slo_attainment (fraction of
                   requests meeting their SLO; no-SLO requests count as
@@ -131,6 +141,12 @@ class EngineReport:
     prefill_chunks: Optional[float] = None
     requests: Optional[float] = None
     preemptions: Optional[float] = None
+    # work the pooled chunk steps did, and admission waits
+    chunk_positions: Optional[float] = None
+    chunk_valid_positions: Optional[float] = None
+    prefill_positions: Optional[float] = None
+    prefill_positions_adopted: Optional[float] = None
+    queue_wait_s: Optional[Dict[int, float]] = None
     # traffic / SLO
     elapsed_s: Optional[float] = None
     goodput_under_slo: Optional[float] = None
@@ -510,6 +526,8 @@ class PageArena:
         self._key_page: Dict[bytes, int] = {}
         self._page_key: Dict[int, bytes] = {}
         self._promises: Dict[int, List[bytes]] = {}
+        # (slot, logical page) mapped by adoption rather than allocation
+        self._adopted = np.zeros((num_slots, num_blocks), bool)
         self.share_hits = 0        # pages adopted instead of allocated
         self.prefix_lookups = 0    # prefix pages that tried the table
         self.cow_copies = 0        # copy-on-write privatizations
@@ -627,6 +645,7 @@ class PageArena:
             page = self._key_page.get(key) if key is not None else None
             if key is not None:
                 self.prefix_lookups += 1
+            self._adopted[slot, lp] = page is not None
             if page is not None:
                 self._ref[page] += 1
                 self.share_hits += 1
@@ -660,6 +679,7 @@ class PageArena:
         if n:
             self.block_tables[slot, :n] = 0
             self.dirty = True
+        self._adopted[slot] = False
         self._counts[slot] = 0
         self._lengths[slot] = 0
         self._promises.pop(slot, None)
@@ -684,11 +704,20 @@ class PageArena:
                 freed += 1
                 self.invalidate_key(page)
             self.block_tables[slot, lp] = 0
+            self._adopted[slot, lp] = False
         if have > need:
             self._counts[slot] = need
             self.dirty = True
         self._lengths[slot] = min(int(self._lengths[slot]), length)
         return freed
+
+    def adopted_mask(self, slot: int, start: int, end: int) -> np.ndarray:
+        """(end - start,) bool over ``slot``'s token positions
+        [start, end): True where the position's logical page was adopted
+        from a sharer by ``grow`` (its K/V^T already sits in the page), not
+        allocated or privatized by ``cow``."""
+        pos = np.arange(start, end)
+        return self._adopted[slot, (pos % self.ring_len) // self.page_size]
 
     # -- copy-on-write -----------------------------------------------------
 
@@ -713,6 +742,7 @@ class PageArena:
         self._ref[old] -= 1
         self._ref[new] = 1
         self.block_tables[slot, lp] = new
+        self._adopted[slot, lp] = False
         self.cow_copies += 1
         self.dirty = True
         self._note_peak()

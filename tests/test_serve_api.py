@@ -110,7 +110,7 @@ def test_both_spellings_drive_identical_engine_behavior():
     d_old, d_new = rep_old.as_dict(), rep_new.as_dict()
     for key in EngineReport.field_names():
         if key in ("elapsed_s", "goodput_under_slo", "ttft_p50_s",
-                   "ttft_p99_s", "tenants"):
+                   "ttft_p99_s", "tenants", "queue_wait_s"):
             continue                      # wall-clock-derived fields
         assert d_old[key] == d_new[key], key
 
@@ -137,6 +137,8 @@ EXPECTED_FIELDS = (
     "iterations", "dispatches_per_iteration", "unified_compiles",
     "engine_compiles", "prefill_batches", "prefill_chunks", "requests",
     "preemptions",
+    "chunk_positions", "chunk_valid_positions", "prefill_positions",
+    "prefill_positions_adopted", "queue_wait_s",
     "elapsed_s", "goodput_under_slo", "slo_attainment", "ttft_p50_s",
     "ttft_p99_s", "tenants",
 )
