@@ -45,10 +45,12 @@ class BinaryConfig:
     # Execution path for binary matmuls: popcount | mxu | dense | auto
     impl: str = "auto"
     # Execution path for deploy attention scores (q x k^T, Eq. 7):
-    # auto | popcount | mxu | dense.  "auto" resolves to "popcount" —
-    # scores run directly on the packed uint32 words (pad-corrected
-    # ``2*popcount(XNOR) - (d_h + 2*pad)``), never unpacking to ±1;
-    # "mxu"/"dense" keep the unpack paths as selectable bitwise oracles.
+    # auto | popcount | mxu | dense.  "auto" keys on the queries a slot
+    # brings: up to 16 (decode, drafts, verify) scores run directly on the
+    # packed uint32 words (pad-corrected ``2*popcount(XNOR) - (d_h +
+    # 2*pad)``); a prefill chunk of more runs score and context as exact
+    # int8 matmuls on the MXU.  "popcount"/"mxu"/"dense" pin one path as
+    # selectable bitwise oracles.
     score_impl: str = "auto"
     # SPS threshold granularity: layer | head | row
     sps_granularity: str = "head"

@@ -48,8 +48,9 @@ ROW_TABLE = 512  # row-granularity lambda table (paper's l=512); longer rows cla
 # Default q-row chunk for the chunked attention scan.
 Q_CHUNK = 256
 
-# Deploy score-path impls (``SPSAttention.score_impl``): "auto" resolves to
-# "popcount" — see ``SPSAttention._score_impl``.
+# Deploy score-path impls (``SPSAttention.score_impl``): "auto" resolves by
+# the query rows a slot brings, popcount up to 16 and mxu above (chunks of
+# a cache-resuming prefill) — see ``SPSAttention._score_impl``.
 SCORE_IMPLS = ("auto", "popcount", "mxu", "dense")
 
 
@@ -207,12 +208,13 @@ class SPSAttention:
     dtype: Any = jnp.float32
     q_chunk: int = Q_CHUNK
     impl: str = "auto"              # deploy matmul impl (projections / M4)
-    # deploy attention-score impl (q x k^T, Eq. 7).  "auto" resolves to
-    # "popcount": scores stay on the packed uint32 words end to end — no
-    # unpack-to-±1 before the score contraction — with the pad correction
-    # ``c = 2*popcount(XNOR) - (d_h + 2*pad)`` applied in-formula (exact
-    # for every d_h).  "mxu"/"dense" keep the unpack paths selectable as
-    # bitwise oracles; tests pin all three identical.
+    # deploy attention-score impl (q x k^T, Eq. 7).  "auto" keeps the
+    # scores on the packed uint32 words (popcount, with the pad correction
+    # ``c = 2*popcount(XNOR) - (d_h + 2*pad)`` in-formula) wherever a slot
+    # brings at most 16 queries, and runs a cache-resuming chunk of more
+    # as exact int8 matmuls on the MXU (score and context).  Explicit
+    # "popcount"/"mxu"/"dense" pin one path at every width as bitwise
+    # oracles; tests pin them identical.
     score_impl: str = "auto"
     # decode: read the KV cache grouped by kv head instead of materializing
     # a q-heads-wide repeat (G x less cache-sized intermediate traffic)
@@ -498,16 +500,20 @@ class SPSAttention:
 
     # -- deploy shared pieces ----------------------------------------------
 
-    def _score_impl(self) -> str:
-        """Resolve the deploy score-path impl.  Unlike projection 'auto'
-        (M-dependent popcount/mxu split in ``rbmm.resolve_impl``), score
-        'auto' is unconditionally popcount: score operands are *both*
-        packed bit tensors, so the binary-native path saves the ±1 unpack
-        at every sequence length, prefill and decode alike."""
+    def _score_impl(self, rows: int = 0) -> str:
+        """Resolve the deploy score-path impl.  'auto' keys on the query
+        rows a slot brings (``rows``: C of a cache-resuming chunk), with
+        ``rbmm.resolve_impl``'s threshold: up to 16 rows (decode, the
+        draft's steps, speculative verify) the scores stay popcount on the
+        packed words, which saves the ±1 unpack where the contraction is
+        too thin for the MXU; above it ``_chunk_attend`` runs both SPS
+        products as exact integer matmuls on the MXU.  Callers that pass
+        no rows (the static prefill, decode, memory attention) keep
+        popcount."""
         if self.score_impl not in SCORE_IMPLS:
             raise ValueError(f"score_impl must be one of {SCORE_IMPLS}, "
                              f"got {self.score_impl!r}")
-        return "popcount" if self.score_impl == "auto" else self.score_impl
+        return rbmm.resolve_impl(self.score_impl, rows)
 
     def _theta_int(self, params: Params) -> Array:
         """Integer SPS thresholds per q-head (or per head-row table)."""
@@ -702,7 +708,11 @@ class SPSAttention:
         no softmax state the two score blocks combine by plain context
         addition — integer-exact, so chunked == whole-prompt bit-for-bit.
         Chunk rows at/after ``valid[b]`` are pad: they compute garbage for
-        their own positions but are masked out of every real row."""
+        their own positions but are masked out of every real row.
+
+        Up to 16 queries per slot (``_score_impl``) the products run as
+        packed-word popcount; above that ``_chunk_attend_mxu`` computes
+        the same integers as matmuls on the MXU."""
         b, _, c_len, _ = q_bits.shape
         dh = self.head_dim
         w = kc_old.shape[2]
@@ -721,37 +731,83 @@ class SPSAttention:
         if window:
             m_pre = m_pre & (t_old[:, None, None, :] >
                              positions[:, None, :, None] - window)
-        kc_h = self._repeat_kv(kc_old)
-        with jax.named_scope("sps_score"):
-            c_pre = rbmm.rbmm_int(q_bits, kc_h, dh, scheme="xnor",
-                                  impl=self._score_impl())     # (B,H,C,W)
-        probs_pre = jnp.where(m_pre, (c_pre >= th).astype(jnp.uint32),
-                              jnp.uint32(0))
-        probs_p = packing.pack_bits(probs_pre)                 # (B,H,C,W/32)
-        nnz = probs_pre.sum(-1, dtype=jnp.int32)
-        vc_h = self._repeat_kv(vc_old)
-        pc = lax.population_count(
-            probs_p[:, :, :, None, :] & vc_h[:, :, None, :, :]
-        ).astype(jnp.int32).sum(-1)                            # (B,H,C,dh)
-        ctx = 2 * pc - nnz[..., None]
         # intra-chunk causal block
-        k_h = self._repeat_kv(k_bits)
-        with jax.named_scope("sps_score"):
-            c_in = rbmm.rbmm_int(q_bits, k_h, dh, scheme="xnor",
-                                 impl=self._score_impl())      # (B,H,C,C)
         i_idx = jnp.arange(c_len)
         m_in = (i_idx[None, :, None] >= i_idx[None, None, :]) & \
                (i_idx[None, None, :] < valid[:, None, None])
         if window:
             m_in = m_in & (i_idx[None, None, :] >
                            i_idx[None, :, None] - window)
-        probs_in = jnp.where(m_in[:, None],
-                             (c_in >= th).astype(jnp.int32), 0)
+        m_in = m_in[:, None]                                   # (B,1,C,C)
+        impl = self._score_impl(c_len)
+        if impl == "mxu":
+            return self._chunk_attend_mxu(q_bits, k_bits, s_v, kc_old,
+                                          vc_old, th, m_pre, m_in)
+        kc_h = self._repeat_kv(kc_old)
+        with jax.named_scope("sps_score"):
+            c_pre = rbmm.rbmm_int(q_bits, kc_h, dh, scheme="xnor",
+                                  impl=impl)                   # (B,H,C,W)
+        probs_pre = jnp.where(m_pre, (c_pre >= th).astype(jnp.uint32),
+                              jnp.uint32(0))
+        with jax.named_scope("sps_context"), \
+                jax.named_scope("rbmm_popcount"):
+            probs_p = packing.pack_bits(probs_pre)             # (B,H,C,W/32)
+            nnz = probs_pre.sum(-1, dtype=jnp.int32)
+            vc_h = self._repeat_kv(vc_old)
+            pc = lax.population_count(
+                probs_p[:, :, :, None, :] & vc_h[:, :, None, :, :]
+            ).astype(jnp.int32).sum(-1)                        # (B,H,C,dh)
+            ctx = 2 * pc - nnz[..., None]
+        k_h = self._repeat_kv(k_bits)
+        with jax.named_scope("sps_score"):
+            c_in = rbmm.rbmm_int(q_bits, k_h, dh, scheme="xnor",
+                                 impl=impl)                    # (B,H,C,C)
+        probs_in = jnp.where(m_in, (c_in >= th).astype(jnp.int32), 0)
         s_v_h = self._repeat_kv(s_v)
         ctx_in = jnp.einsum("bhck,bhkd->bhcd",
                             probs_in.astype(jnp.float32), s_v_h,
                             preferred_element_type=jnp.float32)
         return ctx + ctx_in.astype(jnp.int32)
+
+    def _chunk_attend_mxu(self, q_bits: Array, k_bits: Array, s_v: Array,
+                          kc_old: Array, vc_old: Array, th: Array,
+                          m_pre: Array, m_in: Array) -> Array:
+        """``_chunk_attend``'s two blocks as int8 matmuls with int32
+        accumulation on the MXU: the same integers, bit for bit.
+
+        Query heads are grouped by KV head, (B, Hkv, G, C, dh), so each
+        KV head's ring is unpacked to ±1 once and the MXU sees G*C rows.
+        Scores are sums of dh products in {-1, +1}, contexts of at most
+        W + C products in {-1, 0, +1}, so int32 is exact.  The threshold
+        and masks consume the score in place and the probabilities leave
+        as {0, 1} int8; probs @ V (±1) gives ``2*popcount - nnz`` of the
+        popcount path directly."""
+        b, h, c_len, _ = q_bits.shape
+        hkv, g, dh = self.num_kv_heads, self.groups, self.head_dim
+
+        def grouped(x):  # (B, H|1, ...) -> (B, Hkv, G, ...) | (B, 1, 1, ...)
+            if x.shape[1] == 1:
+                return x[:, :, None]
+            return x.reshape(x.shape[0], hkv, g, *x.shape[2:])
+
+        def signs(p, k):
+            return packing.unpack_signs(p, k, jnp.int8)
+
+        def dot(spec, x, y):
+            return jnp.einsum(spec, x, y, preferred_element_type=jnp.int32)
+
+        q = grouped(signs(q_bits, dh))                         # (B,Hkv,G,C,dh)
+        th = grouped(th)
+        with jax.named_scope("sps_score"), jax.named_scope("rbmm_mxu"):
+            c_pre = dot("bhgcd,bhwd->bhgcw", q, signs(kc_old, dh))
+            probs_pre = (grouped(m_pre) & (c_pre >= th)).astype(jnp.int8)
+            c_in = dot("bhgcd,bhkd->bhgck", q, signs(k_bits, dh))
+            probs_in = (grouped(m_in) & (c_in >= th)).astype(jnp.int8)
+        v_pre = signs(vc_old, kc_old.shape[2])                 # (B,Hkv,dh,W)
+        with jax.named_scope("sps_context"), jax.named_scope("rbmm_mxu"):
+            ctx = dot("bhgcw,bhdw->bhgcd", probs_pre, v_pre) + \
+                dot("bhgck,bhkd->bhgcd", probs_in, s_v.astype(jnp.int8))
+        return ctx.reshape(b, h, c_len, dh)
 
     def deploy_prefill_chunk(self, params: Params, x: Array, cache, *,
                              window=None, start: Optional[Array] = None,

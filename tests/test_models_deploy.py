@@ -1,5 +1,7 @@
 """Deploy-face invariants per arch family (DESIGN.md §7.6/7.7):
 QAT forward == packed deploy forward; decode step t == prefill position t."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -170,3 +172,90 @@ def test_grouped_decode_pad_correction(dh):
     og, _ = a_g.deploy_decode(params, step, cache)
     ou, _ = a_u.deploy_decode(params, step, cache)
     np.testing.assert_array_equal(np.asarray(og), np.asarray(ou))
+
+
+
+def _chunk_attend_case(groups, dh, c_len, w, seed):
+    """An attention with 2 KV heads of ``groups`` query heads each, its
+    deploy params with integer thresholds in [-4, 4], and random packed
+    operands of ``_chunk_attend`` over a contiguous ring of W slots:
+    slot 0's ring is empty, slot 1's partly filled, slot 2's wrapped;
+    slots 1 and 2 bring fewer than C real queries (slot 2 maybe none)."""
+    from repro.core import packing
+    from repro.models.attention import SPSAttention
+    rng = np.random.default_rng(seed)
+    attn = SPSAttention(d_model=64, num_heads=2 * groups, num_kv_heads=2,
+                        head_dim=dh)
+    h, hkv, b = attn.num_heads, attn.num_kv_heads, 3
+    params = attn.convert(attn.init(jax.random.PRNGKey(seed)))
+    scale = (params["q_alpha"] * jnp.repeat(params["k_alpha"], groups)
+             / np.sqrt(dh))
+    params["sps_lambda"] = (scale * rng.integers(-4, 5, h)
+                            - 1e-3 * scale)      # ceil -> the integer
+
+    def bits(*shape):
+        return packing.pack_bits(jnp.asarray(rng.integers(0, 2, shape),
+                                             jnp.uint32))
+
+    start = jnp.asarray([0, rng.integers(1, w), w + rng.integers(1, w)],
+                        jnp.int32)
+    operands = dict(
+        q_bits=bits(b, h, c_len, dh), k_bits=bits(b, hkv, c_len, dh),
+        s_v=jnp.asarray(rng.choice([-1.0, 1.0], (b, hkv, c_len, dh)),
+                        jnp.float32),
+        kc_old=bits(b, hkv, w, dh), vc_old=bits(b, hkv, dh, w),
+        start=start,
+        valid=jnp.asarray([c_len, rng.integers(1, c_len),
+                           rng.integers(0, c_len)], jnp.int32),
+        positions=start[:, None] + jnp.arange(c_len)[None, :])
+    return attn, params, operands
+
+
+@pytest.mark.parametrize("window", [None, 48])
+@pytest.mark.parametrize("groups", [1, 4])
+@pytest.mark.parametrize("dh", [64, 48])
+@pytest.mark.parametrize("c_len", [5, 128])
+def test_chunk_attend_mxu_equals_popcount(c_len, dh, groups, window):
+    """``_chunk_attend``'s integer context is bit-identical whether both
+    SPS products run as packed-word popcount or as int8 matmuls on the
+    MXU (query heads grouped by KV head), over empty, partial and wrapped
+    rings and pad rows.  dh=48 keeps the Eq. 7 pad bits live."""
+    w = 96
+    attn, params, operands = _chunk_attend_case(groups, dh, c_len, w,
+                                                seed=c_len + dh + groups)
+    ctx = {}
+    for si in ("popcount", "mxu"):
+        a = dataclasses.replace(attn, score_impl=si)
+        ctx[si] = jax.jit(lambda p, o, a=a: a._chunk_attend(
+            p, **o, ring=w, window=window))(params, operands)
+    assert ctx["mxu"].dtype == jnp.int32
+    c = np.asarray(ctx["popcount"])
+    assert (c != 0).any() and (c != c.flat[0]).any()
+    np.testing.assert_array_equal(c, np.asarray(ctx["mxu"]))
+
+
+def test_score_impl_auto_resolves_by_chunk_width():
+    """'auto' runs a 128-query chunk's SPS score and context on the MXU
+    and keeps a 5-query verify chunk on popcount: the named scopes in
+    the lowered programs' op metadata say which path each one took."""
+    attn = _mini_attn(head_dim=32)
+    params = attn.convert(attn.init(jax.random.PRNGKey(0)))
+    cache = attn.init_cache(2, 256)
+
+    def scopes(fn, c_len):
+        x = jnp.zeros((2, c_len, 64), jnp.float32)
+        text = jax.jit(fn).lower(params, x, cache).as_text(debug_info=True)
+        return {s: s in text for s in (
+            "sps_score/rbmm_mxu", "sps_context/rbmm_mxu",
+            "sps_score/rbmm_popcount", "sps_context/rbmm_popcount")}
+
+    chunk = scopes(lambda p, x, c: attn.deploy_prefill_chunk(p, x, c), 128)
+    verify = scopes(lambda p, x, c: attn.deploy_verify_chunk(p, x, c), 5)
+    assert chunk == {"sps_score/rbmm_mxu": True,
+                     "sps_context/rbmm_mxu": True,
+                     "sps_score/rbmm_popcount": False,
+                     "sps_context/rbmm_popcount": False}
+    assert verify == {"sps_score/rbmm_mxu": False,
+                      "sps_context/rbmm_mxu": False,
+                      "sps_score/rbmm_popcount": True,
+                      "sps_context/rbmm_popcount": True}
